@@ -1,11 +1,12 @@
 """Kernel-layer microbenchmarks (CPU wall-clock of the jnp reference paths;
-Pallas kernels are TPU-targeted and correctness-checked here via interpret
-mode.  Interpret timings are an emulation, but the per-step vs whole-sequence
-LSTM comparison is still structurally meaningful: the per-step path pays T
-kernel invocations and T weight re-streams, the sequence kernel one — the
-same ratio that dominates on hardware.  Likewise the layerwise-vs-fused
-stack comparison: the layerwise path pays L launches and L inter-layer
-hidden-sequence round-trips per utterance, the fused wavefront one)."""
+Pallas kernels are TPU-targeted: compiled on a TPU, interpreted elsewhere —
+the mode is derived from the platform.  Interpret timings are an emulation,
+but the per-step vs whole-sequence LSTM comparison is still structurally
+meaningful: the per-step path pays T kernel invocations and T weight
+re-streams, the sequence kernel one — the same ratio that dominates on
+hardware.  Likewise the layerwise-vs-fused stack comparison: the layerwise
+path pays L launches and L inter-layer hidden-sequence round-trips per
+utterance, the fused wavefront one)."""
 import time
 
 import jax
@@ -33,12 +34,12 @@ def _lstm_seq_vs_step(T: int = 128, B: int = 8):
     t_scan = time_call(f_scan, p, xs, warmup=1, iters=3)
     emit('kernels/lstm_layer_xla_scan', t_scan, tag)
 
-    f_step = jax.jit(lambda q, x: lstm_layer_step(q, x, interpret=True))
+    f_step = jax.jit(lambda q, x: lstm_layer_step(q, x))
     t_step = time_call(f_step, p, xs, warmup=1, iters=3)
     emit('kernels/lstm_layer_pallas_step', t_step,
          f'{tag} (T kernel launches, W re-streamed per step)')
 
-    f_seq = jax.jit(lambda q, x: lstm_layer_seq(q, x, interpret=True)[0])
+    f_seq = jax.jit(lambda q, x: lstm_layer_seq(q, x)[0])
     t_seq = time_call(f_seq, p, xs, warmup=1, iters=3)
     emit('kernels/lstm_layer_pallas_seq', t_seq,
          f'{tag} (1 launch, weight-stationary; '
@@ -106,11 +107,11 @@ def _lstm_stack_quantized_fused(T: int = 32, B: int = 4):
     def chain(x):
         h = x
         for qp in qps:
-            h = lstm_layer_seq_quantized(qp, h, interpret=True)
+            h = lstm_layer_seq_quantized(qp, h)
         return h
 
     f_lw = jax.jit(chain)
-    f_fu = jax.jit(lambda x: lstm_stack_seq_quantized(qps, x, interpret=True))
+    f_fu = jax.jit(lambda x: lstm_stack_seq_quantized(qps, x))
     same = bool(jnp.all(f_lw(xs_q) == f_fu(xs_q)))
     assert same, 'int8 fused stack must be bit-identical to the chain'
     t_lw, t_fu = [], []

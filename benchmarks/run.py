@@ -55,8 +55,11 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     import jax
+    from repro.compile_cache import enable_compile_cache
+
     from . import common
 
+    enable_compile_cache()
     common.RESULTS.clear()        # idempotent across in-process invocations
     suites = _suites()
     names = args.suite or list(suites)

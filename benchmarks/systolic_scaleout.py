@@ -44,14 +44,11 @@ tests/_subproc.py — and re-emits the rows they print.  CPU host devices make
 the absolute times an emulation, but the compared pairs share per-step
 structure, so the ratios are structurally meaningful.
 """
-import os
-import pathlib
 import subprocess
 import sys
 
 from .common import emit
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
 N_DEVICES = 20      # the 123->421 plan at tile=128 is a 4x5 engine grid
 N_DEVICES_STAGED = 50   # 2 stages x (5x5) == one flat 5x10 grid
 
@@ -234,11 +231,9 @@ print(f'ROW|scaleout/geometry_winner|{win_us:.1f}|'
 
 
 def _run_snippet(snippet: str, n_devices: int):
-    env = dict(os.environ)
-    env['XLA_FLAGS'] = f'--xla_force_host_platform_device_count={n_devices}'
-    env['PYTHONPATH'] = (str(REPO / 'src') + os.pathsep
-                         + env.get('PYTHONPATH', ''))
-    proc = subprocess.run([sys.executable, '-c', snippet], env=env,
+    from repro.launch.mesh import host_device_env
+    proc = subprocess.run([sys.executable, '-c', snippet],
+                          env=host_device_env(n_devices),
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
         raise RuntimeError(f'scaleout subprocess failed\nSTDOUT:\n'

@@ -17,5 +17,5 @@ if __name__ == '__main__':
     ap.add_argument('--slots', type=int, default=4)
     ap.add_argument('--max-new', type=int, default=8)
     a = ap.parse_args()
-    serve_main(['--arch', a.arch, '--requests', str(a.requests),
+    serve_main(['--arch', a.arch, '--smoke', '--requests', str(a.requests),
                 '--slots', str(a.slots), '--max-new', str(a.max_new)])

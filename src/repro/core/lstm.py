@@ -542,7 +542,7 @@ def lstm_layer_fused(params: LSTMParams, xs: jax.Array,
         hs, (h_T, c_T) = step_layer(
             params, xs.reshape(T, flat_b, params.n_x),
             h0=h0.reshape(flat_b, n_h), c0=c0.reshape(flat_b, n_h),
-            return_state=True, interpret=jax.default_backend() != 'tpu')
+            return_state=True)
         return (hs.reshape((T,) + batch_shape + (n_h,)),
                 (h_T.reshape(batch_shape + (n_h,)),
                  c_T.reshape(batch_shape + (n_h,))))
@@ -710,14 +710,19 @@ def resolve_serving_backend(params: LSTMStackParams, backend: str,
     ahead of time on a shape placeholder.  The fault-tolerant serving
     runtime pins this at engine construction so it knows its position on
     the ``DEGRADATION_LADDER`` before any fault occurs.  Pure dispatch —
-    resolution never changes numerics."""
+    resolution never changes numerics.
+
+    Serving chunks are always masked, and ``pallas_step`` has no masked
+    form: where it would be picked (a chunk shorter than ``_SEQ_MIN_T`` on
+    a TPU, or an explicit request) this resolves to ``xla_scan``, the
+    engine that actually runs, so the pinned backend never hides it."""
     l0 = params.layers[0]
     xs = jax.ShapeDtypeStruct((T, B, l0.n_x), jnp.float32)
     resolved = _resolve_stack_backend(params, backend, xs)
     if resolved == 'auto':           # structurally fused-incompatible stack:
         # the per-layer rules decide, exactly as lstm_layer_chunk would
         resolved = select_lstm_backend(l0.n_x, l0.n_h, T, B)
-    return resolved
+    return 'xla_scan' if resolved == 'pallas_step' else resolved
 
 
 def lstm_stack_apply(params: LSTMStackParams, xs: jax.Array,

@@ -17,9 +17,8 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..compat import shard_map
 
 from .lstm import GATES, I, F, G, O, PEEP_I, PEEP_F, PEEP_O, LSTMParams
 from .systolic import PackedLSTM, SystolicPlan, pack_lstm

@@ -63,9 +63,9 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
-from ..compat import AxisType, mesh_with_axis_types, shard_map
 from . import quant
 from .lstm import GATES, I, F, G, O, PEEP_I, PEEP_F, PEEP_O, LSTMParams
 
@@ -514,8 +514,8 @@ def make_systolic_mesh(rows: int, cols: int, stage: int = 1,
     if len(devices) < need:
         raise ValueError(f'need {need} devices, have {len(devices)}')
     arr = np.array(devices[:need]).reshape(stage, rows, cols)
-    return mesh_with_axis_types(arr, ('stage', 'row', 'col'),
-                                axis_types=(AxisType.Auto,) * 3)
+    return Mesh(arr, ('stage', 'row', 'col'),
+                axis_types=(AxisType.Auto,) * 3)
 
 
 def shard_packed_lstm(packed: PackedLSTM, mesh: Mesh) -> PackedLSTM:

@@ -5,9 +5,10 @@ Each kernel lives in its own subpackage with:
   ops.py    — jit'd public wrapper (padding, calibration, layer helpers)
   ref.py    — pure-jnp oracle used by the test sweeps
 
-Kernels are validated on CPU with interpret=True; the production dry-run uses
-the pure-JAX equivalents (``use_pallas=False``) since the CPU backend cannot
-lower Mosaic kernels.
+Interpret mode is derived from the platform (``_platform.resolve_interpret``):
+kernels are compiled by Mosaic on a TPU and interpreted on the CPU, where
+tests validate them.  The production dry-run uses the pure-JAX equivalents
+(``use_pallas=False``) since the CPU backend cannot lower Mosaic kernels.
 """
 from . import flash_attention, lstm_gates, lstm_seq, quant_matmul
 

@@ -26,3 +26,39 @@ def pad_axis_to(x: jax.Array, size: int, axis: int) -> jax.Array:
 def pad_axis_to_multiple(x: jax.Array, mult: int, axis: int) -> jax.Array:
     """Zero-pad ``axis`` up to the next multiple of ``mult``."""
     return pad_axis_to(x, round_up(x.shape[axis], mult), axis)
+
+
+LANES = 128   # TPU vreg lane width: a block's last dim must be a multiple
+
+
+def lane_tile(tile: int) -> int:
+    """Width an engine tile occupies in the int8 kernels' VMEM layout: the
+    silicon's tile (96 for Chipmunk) rounded up to whole 128-lane vregs."""
+    return round_up(tile, LANES)
+
+
+def pad_tiles(x: jax.Array, tile: int, axis: int) -> jax.Array:
+    """Zero-pad every ``tile``-wide block of ``axis`` to ``lane_tile(tile)``.
+
+    A pure relayout: the padded lanes hold zeros, so an integer MAC against
+    zero-padded weights is exact and drops nothing."""
+    tp = lane_tile(tile)
+    if tp == tile:
+        return x
+    axis %= x.ndim
+    n = x.shape[axis] // tile
+    x = x.reshape(x.shape[:axis] + (n, tile) + x.shape[axis + 1:])
+    x = pad_axis_to(x, tp, axis + 1)
+    return x.reshape(x.shape[:axis] + (n * tp,) + x.shape[axis + 2:])
+
+
+def unpad_tiles(x: jax.Array, tile: int, axis: int) -> jax.Array:
+    """Inverse of ``pad_tiles``: keep the first ``tile`` lanes of each block."""
+    tp = lane_tile(tile)
+    if tp == tile:
+        return x
+    axis %= x.ndim
+    n = x.shape[axis] // tp
+    x = x.reshape(x.shape[:axis] + (n, tp) + x.shape[axis + 1:])
+    x = jax.lax.slice_in_dim(x, 0, tile, axis=axis + 1)
+    return x.reshape(x.shape[:axis] + (n * tile,) + x.shape[axis + 2:])

@@ -6,13 +6,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .._platform import resolve_interpret
 from .kernel import flash_attention
 from .ref import attention_ref
 
 
 def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
         window: Optional[int] = None, sm_scale: Optional[float] = None,
-        use_pallas: bool = True, interpret: bool = True,
+        use_pallas: bool = True, interpret: Optional[bool] = None,
         bq: int = 128, bk: int = 128) -> jax.Array:
     """Multi-head attention, auto-padding sequence dims to block multiples."""
     if not use_pallas:
@@ -29,5 +30,6 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     out = flash_attention(qp, kp, vp, causal=causal, window=window,
                           sm_scale=sm_scale, kv_len=sk if pad_k else None,
                           offset=(sk - sq) if causal else 0,
-                          bq=bq_eff, bk=bk_eff, interpret=interpret)
+                          bq=bq_eff, bk=bk_eff,
+                          interpret=resolve_interpret(interpret))
     return out[:, :, :sq]

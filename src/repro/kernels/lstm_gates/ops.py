@@ -10,13 +10,15 @@ import jax.numpy as jnp
 
 from ...core.lstm import LSTMParams, lstm_bwd_recompute_gates
 from .._padding import pad_axis_to, pad_axis_to_multiple, round_up
+from .._platform import resolve_interpret
 from .kernel import lstm_gates, lstm_gates_rec
 from .ref import lstm_gates_ref
 
 
 def lstm_cell_fused(params: LSTMParams, x_t: jax.Array, h_prev: jax.Array,
                     c_prev: jax.Array, *, bn: int = 128, bk: int = 128,
-                    use_pallas: bool = True, interpret: bool = True):
+                    use_pallas: bool = True,
+                    interpret: Optional[bool] = None):
     """Same contract as core.lstm.lstm_cell, via the fused kernel."""
     n_h, n_x = params.n_h, params.n_x
     w = jnp.concatenate([params.w_x, params.w_h], axis=-1)  # (4, N_h, N_in)
@@ -32,7 +34,7 @@ def lstm_cell_fused(params: LSTMParams, x_t: jax.Array, h_prev: jax.Array,
     bias_p = pad_axis_to_multiple(params.b, bn, 1)
     c_p = pad_axis_to(pad_axis_to_multiple(c_prev, bn, 1), b_pad, 0)
     h, c = lstm_gates(xh_p, w_p, peep_p, bias_p, c_p, bn=bn, bk=bk,
-                      interpret=interpret)
+                      interpret=resolve_interpret(interpret))
     return h[:b, :n_h], c[:b, :n_h]
 
 
@@ -91,7 +93,8 @@ def lstm_layer_fused(params: LSTMParams, xs: jax.Array, *,
                      h0: Optional[jax.Array] = None,
                      c0: Optional[jax.Array] = None,
                      bn: int = 128, bk: int = 128, use_pallas: bool = True,
-                     interpret: bool = True, return_state: bool = False):
+                     interpret: Optional[bool] = None,
+                     return_state: bool = False):
     """Scan the fused cell over time.  xs: (T, B, N_x).
 
     Everything per-step-invariant is hoisted out of the scan body: weight
@@ -121,6 +124,7 @@ def lstm_layer_fused(params: LSTMParams, xs: jax.Array, *,
         return (hs, (h_T, c_T)) if return_state else hs
 
     pre_x = jnp.einsum('ghx,tbx->tbgh', params.w_x, xs)      # wide matmul
-    hs, state = lstm_step_fused((bn, bk, bool(interpret)), params.w_h,
+    hs, state = lstm_step_fused((bn, bk, resolve_interpret(interpret)),
+                                params.w_h,
                                 params.w_peep, params.b, pre_x, h0, c0)
     return (hs, state) if return_state else hs

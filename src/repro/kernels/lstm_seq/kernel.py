@@ -53,6 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core import quant
 from ...core.systolic import ACC_FMT, CELL_FMT
+from .._platform import vmem_compiler_params
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +101,9 @@ def _seq_kernel(pre_x_ref, w_ref, peep_ref, bias_ref, h0_ref, c0_ref,
         h_new = o * jnp.tanh(c_new)
         # Masked step = identity on the resident state (select, no arithmetic
         # — the all-ones mask path stays bit-identical to the unmasked form).
-        m = (mask_ref[0] > 0)[:, None]                         # (B, 1)
+        # The (B, 1) mask column is broadcast while still numeric: Mosaic
+        # cannot relayout a boolean vector into a column.
+        m = jnp.broadcast_to(mask_ref[0], h_new.shape) > 0     # (B, bn)
         h_new = jnp.where(m, h_new, h_scr[t % 2, :, sl])
         c_new = jnp.where(m, c_new, c_prev)
         h_scr[(t + 1) % 2, :, sl] = h_new
@@ -134,6 +137,9 @@ def lstm_seq(pre_x: jax.Array, w_h: jax.Array, peep: jax.Array,
     assert b % bb == 0, (b, bb)
     if mask is None:
         mask = jnp.ones((T, b), pre_x.dtype)
+    # (T, B, 1): a (1, bb, 1) block keeps the TPU (8, 128) block rule (the
+    # last dim equals the array's), which a (1, bb) block of (T, B) breaks.
+    mask = mask.astype(jnp.float32).reshape(T, b, 1)
     n_k = n_h // bk
 
     hs, cs = pl.pallas_call(
@@ -147,7 +153,7 @@ def lstm_seq(pre_x: jax.Array, w_h: jax.Array, peep: jax.Array,
             pl.BlockSpec((4, n_h), lambda nb, t, j, k: (0, 0)),
             pl.BlockSpec((bb, n_h), lambda nb, t, j, k: (nb, 0)),
             pl.BlockSpec((bb, n_h), lambda nb, t, j, k: (nb, 0)),
-            pl.BlockSpec((1, bb), lambda nb, t, j, k: (t, nb)),
+            pl.BlockSpec((1, bb, 1), lambda nb, t, j, k: (t, nb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bb, bn), lambda nb, t, j, k: (t, nb, j)),
@@ -162,6 +168,8 @@ def lstm_seq(pre_x: jax.Array, w_h: jax.Array, peep: jax.Array,
             pltpu.VMEM((bb, n_h), jnp.float32),     # c, updated in place
             pltpu.VMEM((4, bb, bn), jnp.float32),   # gate pre-act accumulator
         ],
+        compiler_params=vmem_compiler_params(
+            w_h.size * w_h.dtype.itemsize + 3 * bb * n_h * 4),
         interpret=interpret,
     )(pre_x, w_h, peep, bias, h0, c0, mask)
     return hs, cs
@@ -173,6 +181,21 @@ def lstm_seq(pre_x: jax.Array, w_h: jax.Array, peep: jax.Array,
 
 _sat16 = quant.saturate_int16
 _rshift_round = quant.rshift_round
+
+
+def apply_lut_ref(lut_ref, q: jax.Array) -> jax.Array:
+    """``quant.apply_lut`` inside a kernel, on STATE_FMT codes ``q``.
+
+    ``lut_ref`` is a (1, 256) int32 table in SMEM.  Mosaic cannot lower the
+    gather, so the lookup is 256 selects against scalar table entries —
+    exactly ``lut[q + 128]``, bit for bit.  Returns int32.
+    """
+    idx = q.astype(jnp.int32) + 2 ** (quant.STATE_FMT.bits - 1)
+
+    def pick(k, acc):
+        return jnp.where(idx == k, lut_ref[0, k], acc)
+
+    return jax.lax.fori_loop(0, lut_ref.shape[1], pick, jnp.zeros_like(idx))
 
 
 def _seq_kernel_q(xs_ref, w_ref, peep_ref, bias_ref, sig_ref, tanh_ref,
@@ -194,17 +217,16 @@ def _seq_kernel_q(xs_ref, w_ref, peep_ref, bias_ref, sig_ref, tanh_ref,
 
     # Column input: x-region columns consume the streamed frame slice, h-region
     # columns read the resident hidden state (the chip's vertical re-broadcast).
-    h_off = jnp.maximum(c - cols_x, 0) * tile
-    h_col = jax.lax.dynamic_slice(h_scr[t % 2], (0, h_off),
-                                  (h_scr.shape[1], tile))
-    col_in = jnp.where(c < cols_x, xs_ref[0], h_col).astype(jnp.int32)
+    h_off = pl.multiple_of(jnp.maximum(c - cols_x, 0) * tile, tile)
+    h_col = h_scr[t % 2, :, pl.ds(h_off, tile)]
+    col_in = jnp.where(c < cols_x, xs_ref[0], h_col)
 
-    # Per-engine tile MAC in wide arithmetic, saturated to the 16-bit value an
-    # engine hands to its row neighbour, then the sequential saturating hop.
+    # Per-engine tile MAC — int8 x int8 on the MXU, accumulated exactly in
+    # int32 — saturated to the 16-bit value an engine hands to its row
+    # neighbour, then the sequential saturating hop.
     for g in range(4):
         partial = _sat16(jax.lax.dot_general(
-            col_in, w_ref[g, pl.ds(r * tile, tile),
-                          pl.ds(c * tile, tile)].astype(jnp.int32),
+            col_in, w_ref[g, pl.ds(r * tile, tile), pl.ds(c * tile, tile)],
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32))
         acc_ref[g] = _sat16(acc_ref[g] + partial)
 
@@ -214,36 +236,33 @@ def _seq_kernel_q(xs_ref, w_ref, peep_ref, bias_ref, sig_ref, tanh_ref,
         c_prev32 = c_scr[:, sl].astype(jnp.int32)
         peep32 = peep_ref[:, sl].astype(jnp.int32)
         bias32 = bias_ref[:, sl].astype(jnp.int32)
-        sig_lut = sig_ref[0]
-        tanh_lut = tanh_ref[0]
         shift8 = ACC_FMT.frac_bits - quant.STATE_FMT.frac_bits
 
-        def gate(idx, peep_idx, c_term, lut):
+        def gate(idx, peep_idx, c_term, lut_ref):
             a = acc_ref[idx] + bias32[idx]
             if peep_idx is not None:
                 a = a + peep32[peep_idx] * c_term
             a = _sat16(a)
             a8 = jnp.clip(_rshift_round(a, shift8), -128, 127)
-            return quant.apply_lut(lut, a8, quant.STATE_FMT).astype(jnp.int32)
+            return apply_lut_ref(lut_ref, a8)
 
-        i = gate(0, 0, c_prev32, sig_lut)
-        f = gate(1, 1, c_prev32, sig_lut)
-        g = gate(2, None, None, tanh_lut)
+        i = gate(0, 0, c_prev32, sig_ref)
+        f = gate(1, 1, c_prev32, sig_ref)
+        g = gate(2, None, None, tanh_ref)
         fc = f * c_prev32                        # Q0.7 * Q2.5 -> frac 12
         ig = _rshift_round(i * g, 2)             # frac 14 -> 12
         c_new = _sat16(fc + ig)                  # Q3.12
         c_new8 = jnp.clip(
             _rshift_round(c_new, CELL_FMT.frac_bits - quant.STATE_FMT.frac_bits),
             -128, 127)
-        o = gate(3, 2, c_new8, sig_lut)
-        tanh_c = quant.apply_lut(tanh_lut, c_new8,
-                                 quant.STATE_FMT).astype(jnp.int32)
+        o = gate(3, 2, c_new8, sig_ref)
+        tanh_c = apply_lut_ref(tanh_ref, c_new8)
         h_new = _rshift_round(o * tanh_c, 14 - quant.STATE_FMT.frac_bits)
         h8 = jnp.clip(h_new, -128, 127).astype(jnp.int8)
 
         # Masked step = identity on the resident codes (pure select — the
         # all-ones mask path stays bit-identical to the unmasked datapath).
-        m = (mask_ref[0] > 0)[:, None]
+        m = jnp.broadcast_to(mask_ref[0], h8.shape) > 0
         h8 = jnp.where(m, h8, h_scr[t % 2, :, sl])
         c8 = jnp.where(m, c_new8.astype(jnp.int8), c_scr[:, sl])
 
@@ -288,6 +307,7 @@ def lstm_seq_quantized(xs_q: jax.Array, w_q: jax.Array, peep_q: jax.Array,
         c0_q = jnp.zeros((b, padded_h), jnp.int8)
     if mask is None:
         mask = jnp.ones((T, b), jnp.int8)
+    mask = mask.astype(jnp.int32).reshape(T, b, 1)   # (1, bb, 1) blocks
     n_c = padded_in // tile
 
     return pl.pallas_call(
@@ -300,11 +320,11 @@ def lstm_seq_quantized(xs_q: jax.Array, w_q: jax.Array, peep_q: jax.Array,
                          lambda nb, t, r, c: (0, 0, 0)),
             pl.BlockSpec((3, padded_h), lambda nb, t, r, c: (0, 0)),
             pl.BlockSpec((4, padded_h), lambda nb, t, r, c: (0, 0)),
-            pl.BlockSpec((1, 256), lambda nb, t, r, c: (0, 0)),
-            pl.BlockSpec((1, 256), lambda nb, t, r, c: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # sigmoid LUT
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # tanh LUT
             pl.BlockSpec((bb, padded_h), lambda nb, t, r, c: (nb, 0)),
             pl.BlockSpec((bb, padded_h), lambda nb, t, r, c: (nb, 0)),
-            pl.BlockSpec((1, bb), lambda nb, t, r, c: (t, nb)),
+            pl.BlockSpec((1, bb, 1), lambda nb, t, r, c: (t, nb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bb, tile), lambda nb, t, r, c: (t, nb, r)),
@@ -319,5 +339,7 @@ def lstm_seq_quantized(xs_q: jax.Array, w_q: jax.Array, peep_q: jax.Array,
             pltpu.VMEM((bb, padded_h), jnp.int8),     # c codes
             pltpu.VMEM((4, bb, tile), jnp.int32),     # saturating accumulator
         ],
+        compiler_params=vmem_compiler_params(w_q.size + 3 * bb * padded_h),
         interpret=interpret,
-    )(xs_q, w_q, peep_q, bias_q, sig_lut, tanh_lut, h0_q, c0_q, mask)
+    )(xs_q, w_q, peep_q, bias_q, sig_lut.astype(jnp.int32),
+      tanh_lut.astype(jnp.int32), h0_q, c0_q, mask)

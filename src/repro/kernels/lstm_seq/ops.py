@@ -19,7 +19,9 @@ import jax.numpy as jnp
 from ...core.lstm import (GATES, LSTMParams, lstm_bwd_recompute_gates,
                           valid_len_mask)
 from ...core.systolic import QuantizedPackedLSTM
-from .._padding import pad_axis_to as _pad_to, round_up as _round_up
+from .._padding import (lane_tile, pad_axis_to as _pad_to, pad_tiles,
+                        round_up as _round_up, unpad_tiles)
+from .._platform import resolve_interpret
 from .kernel import lstm_seq, lstm_seq_quantized
 
 
@@ -132,8 +134,7 @@ def lstm_layer_seq(params: LSTMParams, xs: jax.Array,
     """
     assert bb is None or bb % 8 == 0, \
         f'bb={bb} must be a multiple of 8 (f32 sublane tiling)'
-    if interpret is None:
-        interpret = jax.default_backend() != 'tpu'
+    interpret = resolve_interpret(interpret)
     if bn is None or bk is None:
         # Largest block that divides the 128-padded width, so auto blocking
         # never pads beyond what vmem_bytes_estimate (the backend-selection
@@ -152,7 +153,7 @@ def lstm_layer_seq(params: LSTMParams, xs: jax.Array,
         c0 = jnp.zeros(batch_shape + (n_h,), xs.dtype)
     xs_flat = xs.reshape(T, B, params.n_x)
     pre_x = jnp.einsum('ghx,tbx->tbgh', params.w_x, xs_flat)  # hoisted matmul
-    cfg = (bn, bk, bb, bool(interpret))
+    cfg = (bn, bk, bb, interpret)
     if valid_len is not None:
         mask = valid_len_mask(T, valid_len, B)
         hs, cs = _seq_forward(cfg, params.w_h, params.w_peep, params.b,
@@ -173,16 +174,24 @@ def lstm_layer_seq(params: LSTMParams, xs: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _dense_from_tiles(qp: QuantizedPackedLSTM):
-    """(R, C, 4, t, t) engine tiles -> dense (4, R*t, C*t) VMEM layout.
+    """(R, C, 4, t, t) engine tiles -> dense (4, R*tp, C*tp) VMEM layout,
+    each tile zero-padded to ``tp = lane_tile(t)`` lanes (the chip's 96-wide
+    tile is not a whole number of 128-lane vregs, which the TPU block rule
+    needs).
 
-    Pure relayout of the already-quantized codes (no re-rounding), so the
-    kernel consuming it sees bit-for-bit the same weights as the tiled scan.
+    Pure relayout of the already-quantized codes (no re-rounding; padded
+    weights are zero), so the kernel consuming it sees bit-for-bit the same
+    weights as the tiled scan.
     """
     r, c, g, t, _ = qp.tiles_q.shape
-    w = jnp.transpose(qp.tiles_q, (2, 0, 3, 1, 4)).reshape(g, r * t, c * t)
-    peep = jnp.transpose(qp.peep_q, (1, 0, 2)).reshape(3, r * t)
-    bias = jnp.transpose(qp.bias_q, (1, 0, 2)).reshape(4, r * t)
-    return w, peep, bias
+    tp = lane_tile(t)
+    tiles = _pad_to(_pad_to(qp.tiles_q, tp, 3), tp, 4)
+    w = jnp.transpose(tiles, (2, 0, 3, 1, 4)).reshape(g, r * tp, c * tp)
+
+    def rows(v):  # (R, k, t) per-row-block vectors -> (k, R*tp)
+        return jnp.transpose(_pad_to(v, tp, 2), (1, 0, 2)).reshape(-1, r * tp)
+
+    return w, rows(qp.peep_q), rows(qp.bias_q)
 
 
 def lstm_layer_seq_quantized(qp: QuantizedPackedLSTM, xs_q: jax.Array, *,
@@ -206,8 +215,6 @@ def lstm_layer_seq_quantized(qp: QuantizedPackedLSTM, xs_q: jax.Array, *,
     a sequence chunk by chunk is bit-identical to the monolithic call.  With
     ``return_state=True`` returns ``(hs, (h_q, c_q))``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != 'tpu'
     plan = qp.plan
     batch_shape = xs_q.shape[1:-1]
     T = xs_q.shape[0]
@@ -217,20 +224,25 @@ def lstm_layer_seq_quantized(qp: QuantizedPackedLSTM, xs_q: jax.Array, *,
     xs_pad = jnp.zeros((T, b_p, plan.padded_x), jnp.int8
                        ).at[:, :b, :plan.n_x].set(xs_flat)
     h0_q = c0_q = mask = None
+    t = plan.tile
+
+    def carry_in(codes):  # opaque (.., padded_h) carry -> kernel layout
+        z = jnp.zeros((b_p, plan.padded_h), jnp.int8)
+        return pad_tiles(z.at[:b].set(codes.reshape(b, plan.padded_h)), t, 1)
+
     if state is not None:
-        h0_q = jnp.zeros((b_p, plan.padded_h), jnp.int8
-                         ).at[:b].set(state[0].reshape(b, plan.padded_h))
-        c0_q = jnp.zeros((b_p, plan.padded_h), jnp.int8
-                         ).at[:b].set(state[1].reshape(b, plan.padded_h))
+        h0_q, c0_q = map(carry_in, state)
     if valid_len is not None:
         mask = jnp.zeros((T, b_p), jnp.int8).at[:, :b].set(
             valid_len_mask(T, valid_len, b).astype(jnp.int8))
     w_q, peep_q, bias_q = _dense_from_tiles(qp)
     hs, cs = lstm_seq_quantized(
-        xs_pad, w_q, peep_q, bias_q,
+        pad_tiles(xs_pad, t, 2), w_q, peep_q, bias_q,
         qp.sig_lut.reshape(1, 256), qp.tanh_lut.reshape(1, 256),
         h0_q, c0_q, mask,
-        tile=plan.tile, cols_x=plan.cols_x, bb=bb, interpret=bool(interpret))
+        tile=lane_tile(t), cols_x=plan.cols_x, bb=bb,
+        interpret=resolve_interpret(interpret))
+    hs, cs = unpad_tiles(hs, t, 2), unpad_tiles(cs, t, 2)
     out = hs[:, :b, :plan.n_h].reshape((T,) + batch_shape + (plan.n_h,))
     if not return_state:
         return out

@@ -79,6 +79,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core import quant
 from ...core.systolic import ACC_FMT, CELL_FMT
+from .._platform import vmem_compiler_params
+from .kernel import apply_lut_ref
 
 _sat16 = quant.saturate_int16
 _rshift_round = quant.rshift_round
@@ -171,7 +173,9 @@ def _stack_kernel(pre_x_ref, w_in_ref, w_h_ref, peep_ref, bias_ref, h0_ref,
             act = (t >= 0) & (t < T)
             keep = jnp.where(act, h_scr[l, tc % 2, :, sl],
                              h_scr[l, (tc + 1) % 2, :, sl])
-            live = (act & (mask_ref[tc] > 0))[:, None]
+            # The (B, 1) mask column is broadcast while still numeric
+            # (Mosaic cannot relayout a boolean vector into a column).
+            live = act & (jnp.broadcast_to(mask_ref[tc], h_new.shape) > 0)
             h_out = jnp.where(live, h_new, keep)
             c_out = jnp.where(live, c_new, c_prev)
             h_scr[l, (tc + 1) % 2, :, sl] = h_out
@@ -218,6 +222,8 @@ def lstm_stack_seq_kernel(pre_x: jax.Array, w_in: jax.Array, w_h: jax.Array,
     assert L % lb == 0, (L, lb)
     if mask is None:
         mask = jnp.ones((T, b), pre_x.dtype)
+    # (T, B, 1) so the block's last dim equals the array's (TPU block rule)
+    mask = mask.astype(jnp.float32).reshape(T, b, 1)
     n_k = n_h // bk
     D = T + L - 1
 
@@ -238,7 +244,7 @@ def lstm_stack_seq_kernel(pre_x: jax.Array, w_in: jax.Array, w_h: jax.Array,
             pl.BlockSpec((lb, 4, n_h), lambda nb, d, m, j, k: (m, 0, 0)),
             pl.BlockSpec((L, bb, n_h), lambda nb, d, m, j, k: (0, nb, 0)),
             pl.BlockSpec((L, bb, n_h), lambda nb, d, m, j, k: (0, nb, 0)),
-            pl.BlockSpec((T, bb), lambda nb, d, m, j, k: (0, nb)),
+            pl.BlockSpec((T, bb, 1), lambda nb, d, m, j, k: (0, nb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, lb, bb, bn), lambda nb, d, m, j, k: (d, m, nb, j)),
@@ -253,6 +259,11 @@ def lstm_stack_seq_kernel(pre_x: jax.Array, w_in: jax.Array, w_h: jax.Array,
             pltpu.VMEM((L, bb, n_h), jnp.float32),     # c, updated in place
             pltpu.VMEM((lb, bb, 4, bn), jnp.float32),  # gate accumulator
         ],
+        # both weight families of one layer block stay resident (with
+        # lb == L the whole stack: 24 MiB for 3x421 in f32, over Mosaic's
+        # 16 MiB default scoped limit)
+        compiler_params=vmem_compiler_params(
+            2 * lb * w_h[0].size * w_h.dtype.itemsize + 3 * L * bb * n_h * 4),
         interpret=interpret,
     )(pre_x, w_in, w_h, peep, bias, h0, c0, mask)
     return hs, cs
@@ -313,10 +324,10 @@ def _stack_kernel_q(accx_ref, w_ref, peep_ref, bias_ref, sig_ref, tanh_ref,
         below_col = h_scr[max(l - 1, 0), (tc + 1) % 2, :, pl.ds(off_b, tile)]
         own_col = h_scr[l, tc % 2, :, pl.ds(off_o, tile)]
         cols.append(jnp.where(is_below, below_col, own_col))
-    col_in = jnp.stack(cols).astype(jnp.int32)              # (L, bb, tile)
+    col_in = jnp.stack(cols)                                 # (L, bb, tile)
     w_blk = w_ref[:, pl.ds(c * tile, tile), :, pl.ds(r * tile, tile)]
-    partial = _sat16(jax.lax.dot_general(
-        col_in, w_blk.astype(jnp.int32).reshape(L, tile, 4 * tile),
+    partial = _sat16(jax.lax.dot_general(        # int8 MXU, exact int32
+        col_in, w_blk.reshape(L, tile, 4 * tile),
         (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.int32,
     ).reshape(L, col_in.shape[1], 4, tile))
     acc_ref[...] = _sat16(acc_ref[...] + partial)
@@ -324,8 +335,6 @@ def _stack_kernel_q(accx_ref, w_ref, peep_ref, bias_ref, sig_ref, tanh_ref,
     @pl.when(c == n_c - 1)
     def _elementwise():
         sl = pl.ds(r * tile, tile)
-        sig_lut = sig_ref[0]
-        tanh_lut = tanh_ref[0]
         shift8 = ACC_FMT.frac_bits - quant.STATE_FMT.frac_bits
         for l in range(L):
             t = d - l
@@ -336,18 +345,17 @@ def _stack_kernel_q(accx_ref, w_ref, peep_ref, bias_ref, sig_ref, tanh_ref,
             bias32 = bias_ref[l, :, sl].astype(jnp.int32)
             acc_l = acc_ref[l]
 
-            def gate(idx, peep_idx, c_term, lut):
+            def gate(idx, peep_idx, c_term, lut_ref):
                 a = acc_l[:, idx, :] + bias32[idx]
                 if peep_idx is not None:
                     a = a + peep32[peep_idx] * c_term
                 a = _sat16(a)
                 a8 = jnp.clip(_rshift_round(a, shift8), -128, 127)
-                return quant.apply_lut(lut, a8,
-                                       quant.STATE_FMT).astype(jnp.int32)
+                return apply_lut_ref(lut_ref, a8)
 
-            i = gate(0, 0, c_prev32, sig_lut)
-            f = gate(1, 1, c_prev32, sig_lut)
-            g = gate(2, None, None, tanh_lut)
+            i = gate(0, 0, c_prev32, sig_ref)
+            f = gate(1, 1, c_prev32, sig_ref)
+            g = gate(2, None, None, tanh_ref)
             fc = f * c_prev32                    # Q0.7 * Q2.5 -> frac 12
             ig = _rshift_round(i * g, 2)         # frac 14 -> 12
             c_new = _sat16(fc + ig)              # Q3.12
@@ -355,9 +363,8 @@ def _stack_kernel_q(accx_ref, w_ref, peep_ref, bias_ref, sig_ref, tanh_ref,
                 _rshift_round(c_new,
                               CELL_FMT.frac_bits - quant.STATE_FMT.frac_bits),
                 -128, 127)
-            o = gate(3, 2, c_new8, sig_lut)
-            tanh_c = quant.apply_lut(tanh_lut, c_new8,
-                                     quant.STATE_FMT).astype(jnp.int32)
+            o = gate(3, 2, c_new8, sig_ref)
+            tanh_c = apply_lut_ref(tanh_ref, c_new8)
             h_new = _rshift_round(o * tanh_c, 14 - quant.STATE_FMT.frac_bits)
             h_new8 = jnp.clip(h_new, -128, 127).astype(jnp.int8)
 
@@ -365,8 +372,7 @@ def _stack_kernel_q(accx_ref, w_ref, peep_ref, bias_ref, sig_ref, tanh_ref,
             # codes (pure select), with the same write-slot discipline as
             # the f32 kernel: a masked LIVE step re-emits the carried
             # h_{t-1} (slot t%2), a bubble is identity on its WRITE slot.
-            m = act & (mask_ref[tc] > 0)
-            live = m[:, None]
+            live = act & (jnp.broadcast_to(mask_ref[tc], h_new8.shape) > 0)
             keep_h = jnp.where(act, h_scr[l, tc % 2, :, sl],
                                h_scr[l, (tc + 1) % 2, :, sl])
             h8 = jnp.where(live, h_new8, keep_h)
@@ -415,6 +421,7 @@ def lstm_stack_seq_kernel_q(acc_x: jax.Array, w: jax.Array, peep: jax.Array,
     assert w.shape[1] == 2 * cols_h * tile, (w.shape, cols_h, tile)
     if mask is None:
         mask = jnp.ones((T, b), jnp.int8)
+    mask = mask.astype(jnp.int32).reshape(T, b, 1)   # (T, bb, 1) blocks
     R = padded_h // tile
     D = T + L - 1
 
@@ -430,11 +437,11 @@ def lstm_stack_seq_kernel_q(acc_x: jax.Array, w: jax.Array, peep: jax.Array,
                          lambda nb, d, r, c: (0, 0, 0, 0)),
             pl.BlockSpec((L, 3, padded_h), lambda nb, d, r, c: (0, 0, 0)),
             pl.BlockSpec((L, 4, padded_h), lambda nb, d, r, c: (0, 0, 0)),
-            pl.BlockSpec((1, 256), lambda nb, d, r, c: (0, 0)),
-            pl.BlockSpec((1, 256), lambda nb, d, r, c: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # sigmoid LUT
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # tanh LUT
             pl.BlockSpec((L, bb, padded_h), lambda nb, d, r, c: (0, nb, 0)),
             pl.BlockSpec((L, bb, padded_h), lambda nb, d, r, c: (0, nb, 0)),
-            pl.BlockSpec((T, bb), lambda nb, d, r, c: (0, nb)),
+            pl.BlockSpec((T, bb, 1), lambda nb, d, r, c: (0, nb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, L, bb, tile),
@@ -451,5 +458,7 @@ def lstm_stack_seq_kernel_q(acc_x: jax.Array, w: jax.Array, peep: jax.Array,
             pltpu.VMEM((L, bb, padded_h), jnp.int8),     # c codes
             pltpu.VMEM((L, bb, 4, tile), jnp.int32),     # saturating accs
         ],
+        compiler_params=vmem_compiler_params(w.size + 3 * L * bb * padded_h),
         interpret=interpret,
-    )(acc_x, w, peep, bias, sig_lut, tanh_lut, h0, c0, mask)
+    )(acc_x, w, peep, bias, sig_lut.astype(jnp.int32),
+      tanh_lut.astype(jnp.int32), h0, c0, mask)

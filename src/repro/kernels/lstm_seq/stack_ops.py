@@ -25,7 +25,9 @@ from ...core.lstm import (GATES, LSTMStackParams,
                           lstm_stack_bwd_recompute_gates, stack_carry_arrays,
                           valid_len_mask)
 from ...core.systolic import QuantizedPackedLSTM, quantized_x_prefix
-from .._padding import pad_axis_to as _pad_to, round_up as _round_up
+from .._padding import (lane_tile, pad_axis_to as _pad_to, pad_tiles,
+                        round_up as _round_up, unpad_tiles)
+from .._platform import resolve_interpret
 from .ops import _dense_from_tiles
 from .stack_kernel import lstm_stack_seq_kernel, lstm_stack_seq_kernel_q
 
@@ -208,8 +210,7 @@ def lstm_stack_seq(params: LSTMStackParams, xs: jax.Array,
     n_h = layers[0].n_h
     T, B = xs.shape[0], xs.shape[1]
     assert xs.ndim == 3, 'lstm_stack_seq expects (T, B, N_x) input'
-    if interpret is None:
-        interpret = jax.default_backend() != 'tpu'
+    interpret = resolve_interpret(interpret)
     if bn is None or bk is None:
         n_h_p = _round_up(n_h, 128)
         auto = next(b for b in (512, 256, 128) if n_h_p % b == 0)
@@ -225,7 +226,7 @@ def lstm_stack_seq(params: LSTMStackParams, xs: jax.Array,
     if lb is None:
         lb = _tuned_lb(layers[0].n_x, n_h, len(layers), T, B)
     assert lb is None or len(layers) % lb == 0, (len(layers), lb)
-    cfg = (bn, bk, bb, lb, bool(interpret))
+    cfg = (bn, bk, bb, lb, interpret)
 
     if valid_len is not None:
         mask = valid_len_mask(T, valid_len, B)
@@ -264,8 +265,6 @@ def lstm_stack_seq_quantized(qps: Sequence[QuantizedPackedLSTM],
     mask shared by every layer.  Returns the top layer's (T, B, n_h) int8
     hidden codes, plus the state tuple when ``return_state``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != 'tpu'
     plans = [qp.plan for qp in qps]
     p0 = plans[0]
     L = len(qps)
@@ -278,19 +277,23 @@ def lstm_stack_seq_quantized(qps: Sequence[QuantizedPackedLSTM],
     assert xs_q.ndim == 3, 'lstm_stack_seq_quantized expects (T, B, n_x)'
     T, B = xs_q.shape[0], xs_q.shape[1]
     b_p = B if bb is None else _round_up(B, bb)
+    # Kernel layout: every engine tile zero-padded to whole 128-lane vregs
+    # (``_dense_from_tiles``); the opaque state carry keeps the silicon
+    # layout, so it converts on the way in and out.
+    tp = lane_tile(tile)
 
-    # Resident weight relayout: dense (4, padded_h, padded_in) per layer ->
-    # (k, gate, n); inner layers fill the whole 2*cols_h*tile column span,
+    # Resident weight relayout: dense (4, R*tp, C*tp) per layer ->
+    # (k, gate, n); inner layers fill the whole 2*cols_h*tp column span,
     # layer 0 only the own-h region (its x prefix is hoisted into acc_x).
-    w_cols = 2 * cols_h * tile
+    w_cols = 2 * cols_h * tp
     w_all = []
     peep_all, bias_all = [], []
     for l, qp in enumerate(qps):
         dense, peep, bias = _dense_from_tiles(qp)
         if l == 0:
-            w_l = jnp.zeros((GATES, padded_h, w_cols), jnp.int8
-                            ).at[:, :, cols_h * tile:].set(
-                                dense[:, :, plans[0].padded_x:])
+            w_l = jnp.zeros((GATES, dense.shape[1], w_cols), jnp.int8
+                            ).at[:, :, cols_h * tp:].set(
+                                dense[:, :, plans[0].cols_x * tp:])
         else:
             w_l = dense                      # padded_in == 2*cols_h*tile
         w_all.append(jnp.transpose(w_l, (2, 0, 1)))
@@ -304,13 +307,12 @@ def lstm_stack_seq_quantized(qps: Sequence[QuantizedPackedLSTM],
     # sequence — the ONE shared implementation (core.systolic), so the §6
     # and §8 consumers cannot drift apart in saturation or hop order.
     xs_flat = jnp.zeros((T, b_p, p0.n_x), jnp.int8).at[:, :B].set(xs_q)
-    acc_x = quantized_x_prefix(qps[0], xs_flat)
-    if state is None:
-        h0 = jnp.zeros((L, b_p, padded_h), jnp.int8)
-        c0 = jnp.zeros((L, b_p, padded_h), jnp.int8)
-    else:
-        h0 = jnp.zeros((L, b_p, padded_h), jnp.int8).at[:, :B].set(state[0])
-        c0 = jnp.zeros((L, b_p, padded_h), jnp.int8).at[:, :B].set(state[1])
+    acc_x = _pad_to(quantized_x_prefix(qps[0], xs_flat), tp, 4)
+    h0 = jnp.zeros((L, b_p, padded_h), jnp.int8)
+    c0 = jnp.zeros((L, b_p, padded_h), jnp.int8)
+    if state is not None:
+        h0 = h0.at[:, :B].set(state[0])
+        c0 = c0.at[:, :B].set(state[1])
     mask = None
     if valid_len is not None:
         mask = jnp.zeros((T, b_p), jnp.int8).at[:, :B].set(
@@ -319,8 +321,9 @@ def lstm_stack_seq_quantized(qps: Sequence[QuantizedPackedLSTM],
     hs_d, cs_d = lstm_stack_seq_kernel_q(
         acc_x, w_all, peep_all, bias_all,
         qps[0].sig_lut.reshape(1, 256), qps[0].tanh_lut.reshape(1, 256),
-        h0, c0, mask, tile=tile, cols_h=cols_h, bb=bb,
-        interpret=bool(interpret))
+        pad_tiles(h0, tile, 2), pad_tiles(c0, tile, 2), mask, tile=tp,
+        cols_h=cols_h, bb=bb, interpret=resolve_interpret(interpret))
+    hs_d, cs_d = unpad_tiles(hs_d, tile, 3), unpad_tiles(cs_d, tile, 3)
     # Diagonal-major -> layer-major, exactly as in the f32 wrapper: layer
     # l's trajectory is its diagonal band hs_d[l:l+T, l] (pure re-indexing;
     # bubble entries are dropped).

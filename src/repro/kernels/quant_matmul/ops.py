@@ -1,10 +1,13 @@
 """Public op: quantized linear with automatic padding + calibration helpers."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
 from ...core import quant
+from .._platform import resolve_interpret
 from .kernel import quant_matmul
 from .ref import quant_matmul_ref
 
@@ -19,7 +22,8 @@ def _pad_to(x, m, axis):
 
 
 def quantized_linear(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
-                     *, use_pallas: bool = True, interpret: bool = True,
+                     *, use_pallas: bool = True,
+                     interpret: Optional[bool] = None,
                      bm: int = 128, bn: int = 128, bk: int = 128) -> jax.Array:
     """f32/bf16 activations x pre-quantized int8 weights -> f32.
 
@@ -43,7 +47,7 @@ def quantized_linear(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
         xs_p = _pad_to(xs, bm_eff, 0)
         ws_p = _pad_to(ws, bn, 1)
         out = quant_matmul(x_p, w_p, xs_p, ws_p, bm=bm_eff, bn=bn, bk=bk,
-                           interpret=interpret)[:m, :n]
+                           interpret=resolve_interpret(interpret))[:m, :n]
     return out.reshape(lead + (n,))
 
 

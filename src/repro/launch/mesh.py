@@ -7,13 +7,13 @@ crosses DCI; sharding anything over it proves the config scales past one pod.
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from ..compat import AxisType, make_mesh, mesh_with_axis_types
+from jax.sharding import AxisType, Mesh
 
 # TPU v5e constants used for the roofline analysis (per assignment).
 PEAK_FLOPS_BF16 = 197e12      # FLOP/s per chip
@@ -27,7 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Topology construction only; no placement or arithmetic happens here."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ('pod', 'data', 'model') if multi_pod else ('data', 'model')
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 # ---------------------------------------------------------------------------
@@ -55,6 +55,26 @@ SYSTOLIC_TOPOLOGIES = {
     '5x7': (1, 5, 7),        # CTC-3L-421H layer plan at tile=96 (35 engines)
     'graves-75': (3, 5, 5),  # 3-stage pipeline of 5x5 grids = 75 tiles
 }
+
+
+def host_device_env(n_devices: int) -> Dict[str, str]:
+    """Environment for a child process that emulates an ``n_devices`` mesh
+    on forced host (CPU) devices, with this checkout's ``src`` importable.
+
+    Refused on a TPU: the parent holds the chip, so a child that reaches
+    for it fails or hangs — and a mesh of emulated CPU devices says nothing
+    about the chip.  Run such emulations with ``JAX_PLATFORMS=cpu``.
+    """
+    if jax.default_backend() == 'tpu':
+        raise RuntimeError(
+            'forced-host-device emulation is CPU-only: this process holds '
+            'the TPU, so a child process cannot use it; rerun with '
+            'JAX_PLATFORMS=cpu')
+    env = dict(os.environ)
+    env['XLA_FLAGS'] = f'--xla_force_host_platform_device_count={n_devices}'
+    src = str(pathlib.Path(__file__).resolve().parents[2])
+    env['PYTHONPATH'] = src + os.pathsep + env.get('PYTHONPATH', '')
+    return env
 
 
 def make_systolic_topology(name: str, devices=None) -> Mesh:
@@ -156,8 +176,8 @@ class DieMesh:
         form keeps the fault-domain boundary explicit."""
         arr = np.array(list(self.devices)).reshape(
             self.dies, self.stage, self.rows, self.cols)
-        return mesh_with_axis_types(arr, ('die', 'stage', 'row', 'col'),
-                                    axis_types=(AxisType.Auto,) * 4)
+        return Mesh(arr, ('die', 'stage', 'row', 'col'),
+                    axis_types=(AxisType.Auto,) * 4)
 
 
 # name -> (dies, stage-per-die, rows, cols).  'graves-3x25' is the paper's

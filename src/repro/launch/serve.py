@@ -21,9 +21,11 @@ The serving analogue of the paper's deployment story: weights stay resident
     each 5x5 stage holds one layer's weights stationary and the chunk
     pipelines stage to stage via ppermute.
 
-Works on CPU with the smoke configs:
+Serves the named architecture at its full width by default (the paper's
+123 -> 3x421 -> 62 CTC model for ``chipmunk-ctc``); ``--smoke`` swaps in the
+reduced same-family config that CPU tests use:
+  python -m repro.launch.serve --arch chipmunk-ctc --requests 6
   python -m repro.launch.serve --arch qwen3-14b --smoke --requests 6
-  python -m repro.launch.serve --arch chipmunk-ctc --smoke --requests 6
 """
 from __future__ import annotations
 
@@ -37,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import configs
+from ..compile_cache import enable_compile_cache
 from ..models import get_bundle
 from ..serving import SlotScheduler, StreamingEngine
 
@@ -265,8 +268,9 @@ def _run_stream_serving(cfg, args):
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument('--arch', default='qwen3-14b')
-    ap.add_argument('--smoke', action='store_true', default=True)
+    ap.add_argument('--arch', default='chipmunk-ctc')
+    ap.add_argument('--smoke', action='store_true',
+                    help='use the reduced config (CPU-runnable)')
     ap.add_argument('--requests', type=int, default=6)
     ap.add_argument('--slots', type=int, default=3)
     ap.add_argument('--max-new', type=int, default=8)
@@ -349,6 +353,7 @@ def main(argv=None):
                          'ceiling as fallback, recorded to --schedule-cache '
                          'when given; serving itself never pays tuning cost')
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.systolic_topology:
         from .mesh import install_systolic_topology
@@ -373,8 +378,9 @@ def main(argv=None):
               f'from {path}' if path.exists()
               else f'installed empty schedule cache (will tune into {path})')
 
-    cfg = configs.get_smoke_config(args.arch).replace(
-        lstm_backend=args.lstm_backend)
+    cfg = (configs.get_smoke_config(args.arch) if args.smoke
+           else configs.get_config(args.arch)).replace(
+               lstm_backend=args.lstm_backend)
     if args.tune and cfg.family == 'lstm':
         from ..tune import (ScheduleCache, current_schedule_cache,
                             install_schedule_cache, tune_serving_config)

@@ -123,8 +123,8 @@ def local_mesh():
             model = m
             break
     import numpy as np
-    from ..compat import AxisType, mesh_with_axis_types
-    return mesh_with_axis_types(
+    from jax.sharding import AxisType, Mesh
+    return Mesh(
         np.array(jax.devices()).reshape(n // model, model),
         ('data', 'model'), axis_types=(AxisType.Auto, AxisType.Auto))
 
@@ -139,6 +139,7 @@ def main(argv=None):
 
     from .. import configs
     from ..checkpoint import CheckpointManager
+    from ..compile_cache import enable_compile_cache
     from ..data import ShardedLoader, source_for
     from ..models import get_bundle
     from ..runtime import FaultConfig, FaultTolerantRunner
@@ -157,6 +158,7 @@ def main(argv=None):
     ap.add_argument('--microbatches', type=int, default=1)
     ap.add_argument('--log-every', type=int, default=5)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))

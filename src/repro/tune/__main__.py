@@ -26,12 +26,10 @@ failure from inside the subprocess.
 """
 import argparse
 import json
-import os
 import pathlib
 import subprocess
 import sys
 
-REPO = pathlib.Path(__file__).resolve().parents[3]
 
 _STAGED_TUNE_SNIPPET = r"""
 import json, sys
@@ -85,12 +83,9 @@ def _device_budget_error(stages: int, rows: int, cols: int,
 
 
 def _run_tune_subprocess(snippet: str, devices: int):
-    env = dict(os.environ)
-    env['XLA_FLAGS'] = ('--xla_force_host_platform_device_count='
-                        f'{devices}')
-    env['PYTHONPATH'] = (str(REPO / 'src') + os.pathsep
-                         + env.get('PYTHONPATH', ''))
-    proc = subprocess.run([sys.executable, '-c', snippet], env=env,
+    from ..launch.mesh import host_device_env
+    proc = subprocess.run([sys.executable, '-c', snippet],
+                          env=host_device_env(devices),
                           capture_output=True, text=True, timeout=3600)
     if proc.returncode != 0:
         raise RuntimeError(f'tune subprocess failed\nSTDOUT:\n'

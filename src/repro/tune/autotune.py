@@ -391,11 +391,11 @@ def tune_quantized_backend(n_x: int, n_h: int, n_layers: int, T: int, B: int,
     def chain(x):
         h = x
         for qp in qps:
-            h = lstm_layer_seq_quantized(qp, h, interpret=True)
+            h = lstm_layer_seq_quantized(qp, h)
         return h
 
     f_lw = jax.jit(chain)
-    f_fu = jax.jit(lambda x: lstm_stack_seq_quantized(qps, x, interpret=True))
+    f_fu = jax.jit(lambda x: lstm_stack_seq_quantized(qps, x))
     r_lw = np.asarray(jax.block_until_ready(f_lw(xs_q)))
     r_fu = np.asarray(jax.block_until_ready(f_fu(xs_q)))
     np.testing.assert_array_equal(r_lw, r_fu)   # bit-identical by contract

@@ -5,8 +5,7 @@ serving schedules — utterance lengths, priorities, staggered submissions,
 preempt/evict/resume control ops, engine-failure and slot-poison injections
 — and replay the SAME schedule against a synchronous engine, an async
 double-buffered engine, and the monolithic forward, asserting bit-equal
-outputs.  Works with real ``hypothesis`` and with the deterministic stub
-(tests/_hypothesis_stub.py) the CI image falls back to.
+outputs.
 
 Replay is keyed on the engine's COMMITTED step counter (``_step_idx``), not
 the host loop iteration: both dispatch modes pass through every committed
@@ -31,10 +30,7 @@ from __future__ import annotations
 
 import numpy as np
 
-try:
-    from hypothesis import strategies as st
-except ImportError:          # subprocess replays skip conftest's stub install
-    from _hypothesis_stub import strategies as st
+from hypothesis import strategies as st
 
 N_IN_FALLBACK = 13          # smoke config input width (overridden by caller)
 
@@ -48,22 +44,11 @@ def make_utts(lens, n_in):
             for i, L in enumerate(lens)]
 
 
-class _StubMapped:
-    """``.map`` shim for the hypothesis stub's bare strategy objects."""
-
-    def __init__(self, inner, fn):
-        self.draw = lambda rnd: fn(inner.draw(rnd))
-
-
-def _mapped(raw, fn):
-    return raw.map(fn) if hasattr(raw, 'map') else _StubMapped(raw, fn)
-
-
 def op_schedules(max_ops: int = 4):
     """Strategy for control-op schedules: staggered priority submissions
     plus preempt / evict+resume ops keyed on committed step indices.
     Targets are drawn as raw integers and taken mod the stream count at
-    replay, so the strategy needs no dependent draws (stub-compatible)."""
+    replay, so the strategy needs no dependent draws."""
     raw = st.tuples(
         st.lists(st.integers(1, 26), min_size=2, max_size=5),    # lens
         st.lists(st.integers(0, 1), min_size=5, max_size=5),     # priorities
@@ -73,7 +58,7 @@ def op_schedules(max_ops: int = 4):
                            st.integers(0, 7)),                   # raw target
                  min_size=0, max_size=max_ops),
     )
-    return _mapped(raw, _normalize_op_schedule)
+    return raw.map(_normalize_op_schedule)
 
 
 def _normalize_op_schedule(raw):
@@ -99,7 +84,7 @@ def fault_schedules():
         st.lists(st.tuples(st.integers(1, 6), st.integers(0, 2)),
                  min_size=0, max_size=1),                        # poisons
     )
-    return _mapped(raw, _normalize_fault_schedule)
+    return raw.map(_normalize_fault_schedule)
 
 
 def _normalize_fault_schedule(raw):
@@ -130,7 +115,7 @@ def recovery_schedules():
         st.integers(2, 4),                                       # re-fail gap
         st.integers(1, 3),                                       # hysteresis
     )
-    return _mapped(raw, _normalize_recovery_schedule)
+    return raw.map(_normalize_recovery_schedule)
 
 
 def _normalize_recovery_schedule(raw):

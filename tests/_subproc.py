@@ -3,20 +3,19 @@
 Multi-device tests must not set XLA_FLAGS in this process (smoke tests and
 benches must see 1 device), so each distributed test spawns a fresh interpreter.
 """
-import os
 import pathlib
 import subprocess
 import sys
+
+from repro.launch.mesh import host_device_env
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 SRC = str(REPO / 'src')
 
 
 def run_with_devices(snippet: str, n_devices: int, timeout: int = 600) -> str:
-    env = dict(os.environ)
-    env['XLA_FLAGS'] = f'--xla_force_host_platform_device_count={n_devices}'
-    env['PYTHONPATH'] = SRC + os.pathsep + env.get('PYTHONPATH', '')
-    proc = subprocess.run([sys.executable, '-c', snippet], env=env,
+    proc = subprocess.run([sys.executable, '-c', snippet],
+                          env=host_device_env(n_devices),
                           capture_output=True, text=True, timeout=timeout)
     if proc.returncode != 0:
         raise AssertionError(

@@ -1,10 +1,9 @@
-"""Test bootstrap: hypothesis stand-in + a stub-compatible ``timeout`` marker.
+"""Test bootstrap: the test helpers' import path + a ``timeout`` marker.
 
-When the real ``hypothesis`` / ``pytest-timeout`` packages are installed they
-are used as-is; otherwise minimal local fallbacks keep the same test sources
-running (deterministic example drawing, SIGALRM-based timeouts).  The
-``timeout`` marker is what lets a deadlocked async serving step fail fast in
-the serving-conformance CI job instead of hanging the runner.
+When ``pytest-timeout`` is installed it is used as-is; otherwise a
+SIGALRM-based fallback honours the same marker.  The ``timeout`` marker is
+what lets a deadlocked async serving step fail fast in the
+serving-conformance CI job instead of hanging the runner.
 """
 import pathlib
 import sys
@@ -12,13 +11,6 @@ import sys
 import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_stub
-    sys.modules['hypothesis'] = _hypothesis_stub
-    sys.modules['hypothesis.strategies'] = _hypothesis_stub.strategies
 
 try:
     import pytest_timeout  # noqa: F401

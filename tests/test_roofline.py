@@ -74,8 +74,7 @@ def test_collective_parse_multidevice():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.hlo_cost import HloCostModel
-from repro.compat import make_mesh
-mesh = make_mesh((8,), ('d',))
+mesh = jax.make_mesh((8,), ('d',))
 sh = NamedSharding(mesh, P('d'))
 repl = NamedSharding(mesh, P())
 

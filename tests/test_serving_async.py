@@ -5,8 +5,8 @@ for EVERY admission/eviction/preemption/resume/poison/engine-failure
 schedule, its per-stream outputs are bit-equal to the synchronous engine's
 and to the monolithic whole-utterance forward — f32 through the packed
 engine, int8 through the quantized kernels' opaque carries.  Schedules are
-drawn by hypothesis (or the deterministic stub in tests/_hypothesis_stub.py)
-via tests/_serving_strategies.py and replayed against both dispatch modes.
+drawn by hypothesis via tests/_serving_strategies.py and replayed against
+both dispatch modes.
 
 Also here: the §11 chunk-size policy unit contract, commit-time deadline
 accounting under async dispatch (fake clock), the degradation-ladder

@@ -37,7 +37,8 @@ def test_train_driver_end_to_end(tmp_path):
 
 def test_serve_driver_end_to_end():
     out = _run_cli(['-m', 'repro.launch.serve', '--arch', 'qwen3-14b',
-                    '--requests', '3', '--slots', '2', '--max-new', '3'])
+                    '--smoke', '--requests', '3', '--slots', '2',
+                    '--max-new', '3'])
     assert 'served 3 requests' in out
 
 

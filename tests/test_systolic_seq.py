@@ -142,8 +142,7 @@ np.testing.assert_allclose(hs, hs_ref, rtol=1e-5, atol=1e-6)
 systolic.clear_mesh()
 assert lstm.select_lstm_backend(23, 37, 16, 3, platform='cpu') == 'xla_scan'
 # a live non-systolic mesh is rejected, not silently misplaced
-from repro.compat import make_mesh
-dm = make_mesh((1, 2), ('data', 'model'))
+dm = jax.make_mesh((1, 2), ('data', 'model'))
 assert not systolic.seq_scaleout_admissible(37, dm)
 try:
     systolic.systolic_lstm_seq(p, dm, xs)
