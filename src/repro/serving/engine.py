@@ -54,7 +54,8 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Tuple
+from collections import deque
+from typing import Deque, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +70,10 @@ from ..runtime.serving_faults import (ChunkSizePolicy, EngineFailure,
                                       elastic_replace, finite_slots)
 from .scheduler import SlotScheduler
 from .session import IncrementalCTCDecoder, StreamSession
+from .spans import span
+
+# launch-to-commit wall times kept for ``stats()['p50_chunk_s']``
+CHUNK_WALLS_KEPT = 4096
 
 
 def tuned_chunk_ceiling(cfg, chunk: int, max_streams: int) -> int:
@@ -191,7 +196,7 @@ class StreamingEngine:
         self._step_idx = 0
         self._pending: Optional[_InFlight] = None
         self._poison_recorded: set = set()
-        self.chunk_walls: List[float] = []   # per-step wall times (latency)
+        self.chunk_walls: Deque[float] = deque(maxlen=CHUNK_WALLS_KEPT)
         self.events = RingLog(faults.event_log_cap
                               if faults is not None else None)
 
@@ -268,16 +273,17 @@ class StreamingEngine:
         """Queue an utterance ((L, n_in) host frames) for streaming.
         ``priority`` > 0 marks a latency-SLO stream: it is admitted ahead
         of bulk streams and may displace one (§11 priority admission)."""
-        frames = np.asarray(frames, np.float32)
-        assert frames.ndim == 2 and frames.shape[1] == self.cfg.lstm_inputs, \
-            frames.shape
-        if sid is None:
-            sid = self._next_sid
-        self._next_sid = max(self._next_sid, sid + 1)
-        dec = IncrementalCTCDecoder() if self.decode_ctc else None
-        sess = StreamSession(sid=sid, frames=frames, decoder=dec,
-                             priority=priority, t_enqueue=time.time())
-        self.sched.submit(sess)
+        with span('submit'):
+            frames = np.asarray(frames, np.float32)
+            assert (frames.ndim == 2
+                    and frames.shape[1] == self.cfg.lstm_inputs), frames.shape
+            if sid is None:
+                sid = self._next_sid
+            self._next_sid = max(self._next_sid, sid + 1)
+            dec = IncrementalCTCDecoder() if self.decode_ctc else None
+            sess = StreamSession(sid=sid, frames=frames, decoder=dec,
+                                 priority=priority, t_enqueue=time.time())
+            self.sched.submit(sess)
         return sess
 
     def _apply_admission(self, states: tuple, slot: int,
@@ -307,6 +313,15 @@ class StreamingEngine:
             sess.saved_state = None
             self._record('resume', sid=sess.sid, slot=slot,
                          cursor=sess.cursor)
+
+    def _refill(self) -> List[Tuple[int, StreamSession]]:
+        """Admit pending streams into free slots (``sched.refill`` with the
+        ``_admit_slot`` callback) inside a ``serving.admit`` span whose
+        ``streams`` counter is the number admitted."""
+        with span('admit') as sp:
+            admitted = self.sched.refill(self._admit_slot)
+            sp.set_metadata(streams=len(admitted))
+        return admitted
 
     def _snapshot_slot(self, slot: int) -> tuple:
         """Host copy of one slot's per-layer ``(h, c)`` rows — the stream's
@@ -508,10 +523,13 @@ class StreamingEngine:
             if chunk_idx not in self._poison_recorded:
                 self._poison_recorded.add(chunk_idx)
                 self._record('poison_injected', slot=poison, step=chunk_idx)
-        frames, valid = self._pack(plan, chunk_len)
-        frames_j, valid_j = jnp.asarray(frames), jnp.asarray(valid)
-        t0 = time.time()
-        lp, st, fin = self._fwd(self.params, states_in, frames_j, valid_j)
+        with span('pack'):
+            frames, valid = self._pack(plan, chunk_len)
+        with span('dispatch'):
+            frames_j, valid_j = jnp.asarray(frames), jnp.asarray(valid)
+            t0 = time.time()
+            lp, st, fin = self._fwd(self.params, states_in, frames_j,
+                                    valid_j)
         return _InFlight(chunk_idx=chunk_idx,
                          active=[(i, s) for i, s, _ in plan],
                          valid=valid, frames_j=frames_j, valid_j=valid_j,
@@ -532,8 +550,11 @@ class StreamingEngine:
         deadline = self._deadline_for(rec.chunk_len)
 
         def resolve():
-            return (np.asarray(jax.block_until_ready(rec.lp)),
-                    rec.new_states, np.asarray(rec.finite))
+            with span('wait'):
+                jax.block_until_ready(rec.lp)
+            with span('fetch'):
+                return (np.asarray(rec.lp), rec.new_states,
+                        np.asarray(rec.finite))
 
         def retry():
             # the failed futures are dead; recompute synchronously from the
@@ -543,10 +564,13 @@ class StreamingEngine:
             if rec.poison_slot is not None:
                 states_in = jax.tree.map(
                     lambda a: a.at[rec.poison_slot].set(jnp.nan), states_in)
-            lp, st, fin = self._fwd(self.params, states_in,
-                                    rec.frames_j, rec.valid_j)
-            return (np.asarray(jax.block_until_ready(lp)), st,
-                    np.asarray(fin))
+            with span('dispatch'):
+                lp, st, fin = self._fwd(self.params, states_in,
+                                        rec.frames_j, rec.valid_j)
+            with span('wait'):
+                jax.block_until_ready(lp)
+            with span('fetch'):
+                return np.asarray(lp), st, np.asarray(fin)
 
         faulted = False
         if self._runner is not None:
@@ -573,13 +597,14 @@ class StreamingEngine:
         if quarantined:
             new_states = self._quarantine(rec.active, finite, new_states)
         self.states = new_states
-        for i, sess in rec.active:
-            if sess.error is not None:      # quarantined this step
-                continue
-            sess.consume(host[i, :rec.valid[i]])
-            if sess.remaining == 0:
-                sess.t_done = time.time()
-                self.sched.finish(i)
+        with span('deliver'):
+            for i, sess in rec.active:
+                if sess.error is not None:      # quarantined this step
+                    continue
+                sess.consume(host[i, :rec.valid[i]])
+                if sess.remaining == 0:
+                    sess.t_done = time.time()
+                    self.sched.finish(i)
         self._step_idx += 1
         clean = not (quarantined or faulted)
         if clean and self._tracker is not None and self._rung_idx > 0:
@@ -773,11 +798,13 @@ class StreamingEngine:
         k+1 on the in-flight chunk k's output-state futures (host work and
         device compute overlap here), then commit chunk k; adopt the
         speculative launch only if the commit was clean AND the realized
-        admissions match the speculation, else squash it."""
+        admissions match the speculation, else squash it.  The speculative
+        admissions run in a ``serving.admit`` span with no ``streams``
+        counter: the realized refill counts each admitted stream once."""
         if self._pending is None:
             # pipeline fill: plan from committed state, launch, don't commit
             self._maybe_priority_preempt()
-            self.sched.refill(self._admit_slot)
+            self._refill()
             plan = [(i, s, s.cursor) for i, s in self.sched.active()]
             if not plan:
                 return False
@@ -790,13 +817,14 @@ class StreamingEngine:
         if spec is not None:
             plan, admissions = spec
             states_in = rec.new_states
-            for slot, sess in admissions:
-                states_in = self._apply_admission(states_in, slot, sess)
+            with span('admit'):
+                for slot, sess in admissions:
+                    states_in = self._apply_admission(states_in, slot, sess)
             spec_rec = self._launch(states_in, plan, rec.chunk_idx + 1,
                                     self._next_chunk_len())
         clean = self._commit(rec)
         self._maybe_priority_preempt()
-        admitted = self.sched.refill(self._admit_slot)
+        admitted = self._refill()
         if (clean and spec_rec is not None
                 and [(i, s.sid) for i, s in admitted]
                 == [(i, s.sid) for i, s in spec[1]]):
@@ -825,19 +853,24 @@ class StreamingEngine:
         outputs — is committed unless the attempt succeeded, so a retried
         chunk is recomputed from unchanged state.  Returns False when there
         was nothing to do (the drain-loop exit condition).
+
+        The step runs in a ``serving.step`` span, its phases in
+        ``serving.admit``, ``.pack``, ``.dispatch``, ``.wait``, ``.fetch``
+        and ``.deliver`` spans (``serving/spans.py``).
         """
-        self._poll_recovery()
-        if self.async_dispatch:
-            return self._step_async()
-        self._maybe_priority_preempt()
-        self.sched.refill(self._admit_slot)
-        plan = [(i, s, s.cursor) for i, s in self.sched.active()]
-        if not plan:
-            return False
-        rec = self._launch(self.states, plan, self._step_idx,
-                           self._next_chunk_len())
-        self._commit(rec)
-        return True
+        with span('step'):
+            self._poll_recovery()
+            if self.async_dispatch:
+                return self._step_async()
+            self._maybe_priority_preempt()
+            self._refill()
+            plan = [(i, s, s.cursor) for i, s in self.sched.active()]
+            if not plan:
+                return False
+            rec = self._launch(self.states, plan, self._step_idx,
+                               self._next_chunk_len())
+            self._commit(rec)
+            return True
 
     def run(self) -> List[StreamSession]:
         """Drain: step until every submitted stream has been served (the
@@ -854,8 +887,10 @@ class StreamingEngine:
         per-kind counts, deadline-miss total, the current (possibly
         degraded) backend, and the runner's last heartbeat.  §11 additions:
         the dispatch mode and the chunk-size policy's current size/miss
-        count.  Read-only snapshot — an async in-flight chunk is NOT
-        committed by this call."""
+        count.  ``p50_chunk_s`` is the median launch-to-commit wall time of
+        the recent chunks (the last ``CHUNK_WALLS_KEPT``).  Read-only
+        snapshot — an async in-flight chunk is NOT committed by this
+        call."""
         done = self.sched.done
         frames = sum(s.length for s in done)
         lats = [s.t_done - s.t_enqueue for s in done if s.t_done]

@@ -11,7 +11,6 @@ deployment streams out — and its accumulated output equals the monolithic
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import List, Optional
 
 import numpy as np
@@ -73,7 +72,6 @@ class StreamSession:
     priority: int = 0
     log_probs: List[np.ndarray] = dataclasses.field(default_factory=list)
     t_enqueue: float = 0.0
-    t_first: Optional[float] = None
     t_done: Optional[float] = None
     saved_state: Optional[tuple] = None
     error: Optional[str] = None
@@ -97,8 +95,6 @@ class StreamSession:
         n = len(log_probs)
         assert n <= self.remaining, (n, self.remaining)
         self.cursor += n
-        if n and self.t_first is None:
-            self.t_first = time.time()
         if n:
             self.log_probs.append(np.asarray(log_probs))
             if self.decoder is not None:
