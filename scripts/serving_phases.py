@@ -9,8 +9,12 @@ the measured window to one JSON file:
 - ``ms_per_step``: each phase's summed duration over the ``serving.step``
   spans in the window (``submit`` runs outside a step but is divided by the
   same count), and ``idle_ms_per_step``: the part of it with the device idle;
+- ``admitted`` and ``admitting_steps``: the streams the admit spans
+  counted, and how many of those spans admitted any;
 - ``metrics``: ``admit_ms_per_stream`` (admit time over its ``streams``
-  counters), ``launch_ms_per_step`` (pack + dispatch),
+  counters), ``admit_dispatches_per_step`` (the admit spans' ``dispatches``
+  counters over the steps; None where no span carries that counter),
+  ``launch_ms_per_step`` (pack + dispatch),
   ``deliver_ms_per_step`` (fetch + deliver), ``submit_ms_per_step``;
 - ``coverage``: the share of ``serving.step`` time that the phase spans
   cover, and ``step_over_harness_step``: summed ``serving.step`` over summed
@@ -175,13 +179,19 @@ def reduce(events: dict, t) -> dict:
     def per_step(*ks):
         return sum(dur.get(k, 0.0) for k in ks) / n * 1e3
 
-    admitted = sum(c.get('streams', 0) for _, _, c in win.get('admit', []))
+    admits = win.get('admit', [])
+    admitted = sum(c.get('streams', 0) for _, _, c in admits)
     res['admitted'] = admitted
+    res['admitting_steps'] = sum(1 for _, _, c in admits
+                                 if c.get('streams', 0))
+    counted = [c['dispatches'] for _, _, c in admits if 'dispatches' in c]
     res['ms_per_step'] = {k: per_step(k)
                           for k in PHASES + ('submit', 'step')}
     res['metrics'] = {
         'admit_ms_per_stream': (dur.get('admit', 0.0) / admitted * 1e3
                                 if admitted else None),
+        'admit_dispatches_per_step': (sum(counted) / n if counted
+                                      else None),
         'launch_ms_per_step': per_step('pack', 'dispatch'),
         'deliver_ms_per_step': per_step('fetch', 'deliver'),
         'submit_ms_per_step': per_step('submit'),

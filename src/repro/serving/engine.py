@@ -239,6 +239,7 @@ class StreamingEngine:
         self._last_commit: Optional[dict] = None   # canary replay material
         from ..core.systolic import current_mesh
         self._home_mesh = current_mesh()   # re-installed on mesh promotions
+        self._build_admission()
         self._build_fwd()
 
     def _make_fwd(self, cfg):
@@ -260,6 +261,28 @@ class StreamingEngine:
             return lp, new_states, finite
 
         return jax.jit(fwd)
+
+    def _build_admission(self):
+        """The jitted slot initialisations of ``_apply_admissions``, each
+        one device call of a fixed shape: ``_reset_slots(states, mask)``
+        zeroes every row whose ``(S,)`` boolean ``mask`` entry is set (exact
+        zeros, as ``.at[slot].set(0)`` writes), so admitting 1 or all S
+        streams reuses one compiled reset; ``_restore_slot(states, slot,
+        rows)`` writes one resumed stream's saved per-layer ``(h, c)`` rows
+        into ``slot``.  The inputs are not donated: an in-flight chunk's
+        ``states_in`` and the canary's replay material may still hold
+        them."""
+        def reset(states, mask):
+            return jax.tree.map(
+                lambda a: jnp.where(mask[:, None], jnp.zeros((), a.dtype), a),
+                states)
+
+        def restore(states, slot, rows):
+            return tuple((h.at[slot].set(rh), c.at[slot].set(rc))
+                         for (h, c), (rh, rc) in zip(states, rows))
+
+        self._reset_slots = jax.jit(reset)
+        self._restore_slot = jax.jit(restore)
 
     def _build_fwd(self):
         """(Re)build the jitted packed chunk call for the CURRENT backend.
@@ -286,41 +309,54 @@ class StreamingEngine:
             self.sched.submit(sess)
         return sess
 
-    def _apply_admission(self, states: tuple, slot: int,
-                         sess: StreamSession) -> tuple:
+    def _apply_admissions(self, states: tuple,
+                          admissions: List[Tuple[int, StreamSession]]
+                          ) -> tuple:
         """Functional slot initialisation, shared by the realized admission
-        (``_admit_slot``) and the §11 speculative launch composition: a
-        recycled slot must never leak its previous occupant's state — zero
-        its packed rows, or, for a resumed session, scatter the saved
-        per-layer ``(h, c)`` rows back in (an exact host round-trip, so
-        resume is bit-equal to never having been evicted).  Pure functional
-        update — both call sites perform the identical op sequence, which
-        is what keeps the speculative input bit-equal to the committed
-        one."""
-        if sess.saved_state is not None:
-            return tuple(
-                (h.at[slot].set(jnp.asarray(rh)),
-                 c.at[slot].set(jnp.asarray(rc)))
-                for (h, c), (rh, rc) in zip(states, sess.saved_state))
-        return jax.tree.map(lambda a: a.at[slot].set(0), states)
+        (``_refill``) and the §11 speculative launch composition: a recycled
+        slot must never leak its previous occupant's state.  Every fresh
+        stream's packed rows are zeroed by ONE ``_reset_slots`` call over an
+        ``(S,)`` slot mask, whatever the number admitted; a resumed session
+        then gets its saved per-layer ``(h, c)`` rows scattered back in by
+        one ``_restore_slot`` call each (an exact host round-trip, so resume
+        is bit-equal to never having been evicted).  Pure functional update
+        — both call sites perform the identical op sequence, which is what
+        keeps the speculative input bit-equal to the committed one."""
+        fresh = [slot for slot, sess in admissions
+                 if sess.saved_state is None]
+        if fresh:
+            mask = np.zeros((self.sched.num_slots,), bool)
+            mask[fresh] = True
+            states = self._reset_slots(states, mask)
+        for slot, sess in admissions:
+            if sess.saved_state is not None:
+                states = self._restore_slot(states, np.int32(slot),
+                                            sess.saved_state)
+        return states
 
-    def _admit_slot(self, slot: int, sess: StreamSession) -> None:
-        """Admission callback (see ``_apply_admission`` for the numerics):
-        applies the slot initialisation to the committed cache and clears
-        the session's saved rows."""
-        self.states = self._apply_admission(self.states, slot, sess)
-        if sess.saved_state is not None:
-            sess.saved_state = None
-            self._record('resume', sid=sess.sid, slot=slot,
-                         cursor=sess.cursor)
+    @staticmethod
+    def _admission_dispatches(admissions) -> int:
+        """Device calls ``_apply_admissions`` makes for ``admissions``: one
+        reset when any stream is fresh, plus one restore per resumed one."""
+        resumed = sum(sess.saved_state is not None for _, sess in admissions)
+        return int(resumed < len(admissions)) + resumed
 
     def _refill(self) -> List[Tuple[int, StreamSession]]:
-        """Admit pending streams into free slots (``sched.refill`` with the
-        ``_admit_slot`` callback) inside a ``serving.admit`` span whose
-        ``streams`` counter is the number admitted."""
+        """Admit pending streams into free slots (``sched.refill``), then
+        initialise their rows of the committed cache in one batch
+        (``_apply_admissions``) and clear the resumed sessions' saved rows,
+        inside a ``serving.admit`` span whose counters are ``streams``, the
+        number admitted, and ``dispatches``, the device calls that took."""
         with span('admit') as sp:
-            admitted = self.sched.refill(self._admit_slot)
-            sp.set_metadata(streams=len(admitted))
+            admitted = self.sched.refill()
+            sp.set_metadata(streams=len(admitted),
+                            dispatches=self._admission_dispatches(admitted))
+            self.states = self._apply_admissions(self.states, admitted)
+            for slot, sess in admitted:
+                if sess.saved_state is not None:
+                    sess.saved_state = None
+                    self._record('resume', sid=sess.sid, slot=slot,
+                                 cursor=sess.cursor)
         return admitted
 
     def _snapshot_slot(self, slot: int) -> tuple:
@@ -359,7 +395,7 @@ class StreamingEngine:
 
         Neighbouring streams are untouched — their state rows are separate
         slots of the packed cache and the freed row is zeroed on the next
-        admission (``_admit_slot``).  The evicted stream's state is no
+        admission (``_apply_admissions``).  The evicted stream's state is no
         longer silently discarded: its ``(h, c)`` rows + cursor are
         snapshotted onto the session (and to disk when a checkpointer is
         configured), so ``resume``/``resume_from_checkpoint`` can continue
@@ -799,8 +835,9 @@ class StreamingEngine:
         device compute overlap here), then commit chunk k; adopt the
         speculative launch only if the commit was clean AND the realized
         admissions match the speculation, else squash it.  The speculative
-        admissions run in a ``serving.admit`` span with no ``streams``
-        counter: the realized refill counts each admitted stream once."""
+        admissions run in a ``serving.admit`` span with a ``dispatches``
+        counter and no ``streams`` counter: the realized refill counts each
+        admitted stream once."""
         if self._pending is None:
             # pipeline fill: plan from committed state, launch, don't commit
             self._maybe_priority_preempt()
@@ -817,9 +854,10 @@ class StreamingEngine:
         if spec is not None:
             plan, admissions = spec
             states_in = rec.new_states
-            with span('admit'):
-                for slot, sess in admissions:
-                    states_in = self._apply_admission(states_in, slot, sess)
+            with span('admit') as sp:
+                sp.set_metadata(
+                    dispatches=self._admission_dispatches(admissions))
+                states_in = self._apply_admissions(states_in, admissions)
             spec_rec = self._launch(states_in, plan, rec.chunk_idx + 1,
                                     self._next_chunk_len())
         clean = self._commit(rec)

@@ -4,8 +4,9 @@ Both serving front-ends share this policy object: the token ``SlotServer``
 (launch/serve.py) schedules decode requests onto cache slots, and the
 ``StreamingEngine`` (serving/engine.py) schedules frame streams onto rows of
 the packed state cache.  The scheduler owns *which* item occupies *which*
-slot and nothing else — state initialisation happens in the admission
-callback, so the policy is reusable across workloads.
+slot and nothing else — state initialisation is the caller's (in the
+admission callback, or over the returned admissions in one batch, as the
+engine does), so the policy is reusable across workloads.
 
 Priority admission (DESIGN.md §11): items may carry an integer ``priority``
 attribute (higher = more urgent; absent = 0, plain FIFO).  The pending queue

@@ -54,8 +54,8 @@ class StreamSession:
 
     Fault-tolerance fields (DESIGN.md §10): ``saved_state`` holds the
     stream's preempted per-layer ``(h, c)`` rows between eviction and
-    re-admission (scattered back into the packed cache by the engine's
-    admission callback, then cleared); ``error`` is the terminal fault
+    re-admission (written back into the packed cache by the engine's
+    ``_apply_admissions``, then cleared); ``error`` is the terminal fault
     string set when the stream is quarantined — an errored session is never
     retired into ``done`` and must not be resubmitted.
 

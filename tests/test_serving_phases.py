@@ -18,7 +18,8 @@ def spans(*rows, **counters):
 
 EVENTS = {
     'step': spans((0.1, 0.2), (0.5, 0.6), (1.2, 1.3)),   # last: outside
-    'admit': spans((0.1, 0.13), streams=2) + spans((0.5, 0.51), streams=0),
+    'admit': (spans((0.1, 0.13), streams=2, dispatches=1)
+              + spans((0.5, 0.51), streams=0, dispatches=0)),
     'pack': spans((0.13, 0.14), (0.51, 0.52)),
     'dispatch': spans((0.14, 0.15), (0.52, 0.53)),
     'wait': spans((0.15, 0.17), (0.53, 0.56)),
@@ -41,8 +42,10 @@ def trace():
 def test_reduce_splits_the_step_by_phase():
     res = serving_phases.reduce(EVENTS, trace())
     assert res['steps'] == 2 and res['admitted'] == 2
+    assert res['admitting_steps'] == 1
     m = res['metrics']
     assert m['admit_ms_per_stream'] == pytest.approx(20.0)
+    assert m['admit_dispatches_per_step'] == pytest.approx(0.5)
     assert m['launch_ms_per_step'] == pytest.approx(20.0)
     assert m['deliver_ms_per_step'] == pytest.approx(27.5)
     assert m['submit_ms_per_step'] == pytest.approx(5.0)
@@ -52,6 +55,15 @@ def test_reduce_splits_the_step_by_phase():
     assert res['idle_ms_per_step']['dispatch'] == pytest.approx(2.5)
     assert [g[0] for g in res['idle_gaps']] == [
         'step/wait', 'tick_wait/deliver', 'step/admit']
+
+
+def test_admit_spans_without_dispatch_counter_read_none():
+    """Spans from an engine whose admission counts no device calls."""
+    events = dict(EVENTS, admit=spans((0.1, 0.13), streams=2)
+                  + spans((0.5, 0.51), streams=0))
+    m = serving_phases.reduce(events, trace())['metrics']
+    assert m['admit_dispatches_per_step'] is None
+    assert m['admit_ms_per_stream'] == pytest.approx(20.0)
 
 
 def test_clock_matches_each_run_to_its_own_dispatch():
