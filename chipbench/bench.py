@@ -1,7 +1,8 @@
 """Finds a cell's pieces by name: ``BENCHMARK.json`` at the checkout's root
 lists the cells and metrics; each configuration is the file its entry
 names, each traffic mix is ``traffic/<name>.json``, each metric is read by
-``metrics/<name>.py`` and each reference is ``reference/<name>.py``.
+``metrics/<name>.py``, each reference is ``reference/<name>.py`` and each
+model kind (a configuration's ``model.kind``) is ``models/<kind>.py``.
 Adding any of them takes new files and entries, never an edit here."""
 from __future__ import annotations
 
@@ -67,6 +68,15 @@ class Spec:
 
     def reference(self, name: str):
         return load_module(self.dir / 'reference' / f'{name}.py')
+
+    def model(self, config: dict):
+        """The module of the configuration's model kind: its
+        ``make_weights(model, seed)``, ``program(config, weights)`` (the
+        program's architecture and parameters), ``outputs(frames)`` (output
+        rows of a stream of ``frames``), ``flops_per_frame(model)`` and
+        ``chunk_bytes(model, frames, streams, itemsize)``."""
+        kind = config['model']['kind']
+        return load_module(self.dir / 'models' / f'{kind}.py')
 
 
 def load_module(path: pathlib.Path):

@@ -11,7 +11,9 @@ same weights and frames.  Compared, each against the configuration's limit:
   frame;
 * ``symbols_wrong``: streams whose emitted CTC symbols differ from the
   greedy collapse of their own served log-probs (exact: limit 0);
-* ``frames_missing``: frames of sampled streams with no log-probs (exact).
+* ``frames_missing``: output rows of sampled streams with no log-probs
+  (exact); the model kind's ``outputs(frames)`` says how many rows a
+  stream's frames yield.
 
 A configuration's ``limits`` name the numbers compared; the others are
 read and printed only.
@@ -92,14 +94,16 @@ def reference_log_probs(reference, config: dict, weights: dict,
                                           dtype=dtype))
 
 
-def numbers(streams: list, ref_lp: np.ndarray) -> dict:
+def numbers(streams: list, ref_lp: np.ndarray, outputs) -> dict:
     """The compared numbers of served ``streams`` against reference
-    log-probs ``ref_lp`` (rows in the same order)."""
+    log-probs ``ref_lp`` (rows in the same order); ``outputs(frames)`` is
+    the number of output rows a stream of ``frames`` input frames yields."""
     err = gap = total = 0.0
     wrong = missing = count = 0
     for i, (f, lp, symbols) in enumerate(streams):
-        n = min(len(f), len(lp))
-        missing += len(f) - n
+        due = outputs(len(f))
+        n = min(due, len(lp))
+        missing += due - n
         if n == 0:
             continue
         r = ref_lp[i, :n]

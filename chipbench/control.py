@@ -38,11 +38,11 @@ def read(spec, cell: dict, seed: int) -> dict:
     import jax.numpy as jnp
     import numpy as np
     from chipbench import check, harness
-    from chipbench.weights import make_weights
 
     config, mix = spec.config(cell), spec.traffic(cell)
     frame_s = config['serving']['frame_ms'] / 1000
-    weights = jax.block_until_ready(make_weights(config['model'], seed))
+    kind = spec.model(config)
+    weights = jax.block_until_ready(kind.make_weights(config['model'], seed))
     sched = harness.schedule_for(mix, config, seed,
                                  seconds=spec.data['run_seconds'])
     n = min(len(sched), WINDOW_STREAMS)
@@ -61,8 +61,9 @@ def read(spec, cell: dict, seed: int) -> dict:
                                        rows, width)
     ctl_lp = check.reference_log_probs(reference, config, weights, served,
                                        rows, width, dtype=jnp.bfloat16)
-    values = check.numbers([(f, ctl_lp[i, :len(f)], None)
-                            for i, f in enumerate(frames)], ref_lp)
+    values = check.numbers([(f, ctl_lp[i, :kind.outputs(len(f))], None)
+                            for i, f in enumerate(frames)], ref_lp,
+                           kind.outputs)
     correct, checks = check.judge(values, config['limits'])
     return {'workload': cell['name'], 'seed': seed, 'correct': correct,
             'streams': len(frames), 'frames': sum(len(f) for f in frames),

@@ -4,7 +4,9 @@ of what the window produced, and the metrics.
 The system under test is the repository's streaming front end
 (``launch.serve.StreamServer`` over ``serving.StreamingEngine``), driven
 through ``submit`` and ``engine.step()`` only.  The benchmark makes the
-weights and the frames from the seed, and keeps its own reference.
+weights and the frames from the seed, and keeps its own reference; the
+configuration's model kind (``models/<kind>.py``) makes the weights and
+hands them to the program.
 """
 from __future__ import annotations
 
@@ -22,7 +24,6 @@ import numpy as np
 from . import check, loop, trace as trace_mod, traffic
 from .stalls import StallWatch
 from .bench import Spec
-from .weights import make_weights
 
 COMPILE_EVENTS = ('/jax/core/compile/backend_compile_duration',
                   '/jax/core/compile/jaxpr_trace_duration')
@@ -41,6 +42,7 @@ class Run:
     step_frames: np.ndarray        # frames committed by each window step
     step_streams: np.ndarray       # streams that emitted in each window step
     model: dict
+    kind: object                   # the model kind's module
     serving: dict
     peaks: dict
     trace: Optional[trace_mod.Trace] = None
@@ -85,27 +87,6 @@ class GcPauses:
         gc.callbacks.remove(self._on)
 
 
-def program_params(weights: dict):
-    """The benchmark's weights in the program's parameter structure."""
-    from repro.core.lstm import LSTMParams, LSTMStackParams
-    return LSTMStackParams(
-        tuple(LSTMParams(l['w_x'], l['w_h'], l['w_peep'], l['b'])
-              for l in weights['layers']),
-        weights['w_out'], weights['b_out'])
-
-
-def program_config(config: dict):
-    from repro.configs import ArchConfig
-    m = config['model']
-    return ArchConfig(
-        name=config['name'], family='lstm', n_layers=m['layers'],
-        d_model=m['hidden'], n_heads=1, n_kv_heads=1, d_ff=0,
-        vocab_size=m['outputs'], lstm_hidden=m['hidden'],
-        lstm_inputs=m['inputs'], n_outputs=m['outputs'],
-        param_dtype=config['dtype'], activation_dtype=config['dtype'],
-        remat='none', lstm_backend=config['lstm_backend'])
-
-
 def schedule_for(mix: dict, config: dict, seed: int, seconds: float):
     m, s = config['model'], config['serving']
     frame_s = s['frame_ms'] / 1000
@@ -127,12 +108,14 @@ def enable_cache() -> None:
     jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
 
 
-def start_server(config: dict, weights: dict):
-    """The program's streaming front end over the benchmark's weights."""
+def start_server(config: dict, kind, weights: dict):
+    """The program's streaming front end over the benchmark's weights, as
+    the model kind ``kind`` hands them to it."""
     from repro.launch.serve import StreamServer
     serving = config['serving']
-    server = StreamServer(program_config(config), program_params(weights),
-                          num_slots=serving['slots'], chunk=serving['chunk'])
+    arch, params = kind.program(config, weights)
+    server = StreamServer(arch, params, num_slots=serving['slots'],
+                          chunk=serving['chunk'])
     engine = server.engine
     if (engine.decode_ctc, engine.async_dispatch) != (
             serving['decode_ctc'], serving['async_dispatch']):
@@ -167,8 +150,9 @@ def run_cell(spec: Spec, cell: dict, *, seed: int, seconds: float,
     counter, gc_pauses = CompileCounter(), GcPauses()
     watch = StallWatch(progress=lambda: steps[0])
 
-    weights = jax.block_until_ready(make_weights(model, seed))
-    server = start_server(config, weights)
+    kind = spec.model(config)
+    weights = jax.block_until_ready(kind.make_weights(model, seed))
+    server = start_server(config, kind, weights)
     engine = server.engine
     sched = schedule_for(mix, config, seed, seconds)
     em = loop.Emissions(frame_s)
@@ -246,7 +230,7 @@ def run_cell(spec: Spec, cell: dict, *, seed: int, seconds: float,
     longest = int(round(mix['length_s']['max'] / frame_s))
     ref_lp = check.reference_log_probs(reference, config, weights, streams,
                                        rows, longest)
-    values = check.numbers(streams, ref_lp)
+    values = check.numbers(streams, ref_lp, kind.outputs)
     correct, checks = check.judge(values, config['limits'])
     log('read, not compared: ' + ' '.join(
         f'{k} {v:.6g}' for k, v in values.items() if k not in checks))
@@ -263,10 +247,10 @@ def run_cell(spec: Spec, cell: dict, *, seed: int, seconds: float,
         shutil.rmtree(state['trace_dir'], ignore_errors=True)
     run = Run(setup_s=state['setup_s'], window=window, latencies_s=lat,
               step_frames=step_frames, step_streams=step_streams,
-              model=model, serving=serving, peaks=peaks, trace=tr)
-    kind = 'per_layer' if traced else 'end_to_end'
+              model=model, kind=kind, serving=serving, peaks=peaks,
+              trace=tr)
     metrics = {}
-    for m in spec.metrics(cell, kind):
+    for m in spec.metrics(cell, 'per_layer' if traced else 'end_to_end'):
         value = spec.reader(m['name'])(run)
         if value is not None:
             metrics[m['name']] = {'value': value, 'unit': m['unit']}

@@ -39,13 +39,13 @@ def measure(spec, cell, rate: float, seed: int, seconds: float) -> dict:
     import jax
     import numpy as np
     from chipbench import harness, loop
-    from chipbench.weights import make_weights
 
     config = spec.config(cell)
     mix = dict(spec.traffic(cell), rate_per_s=rate)
     serving = config['serving']
-    weights = jax.block_until_ready(make_weights(config['model'], seed))
-    server = harness.start_server(config, weights)
+    kind = spec.model(config)
+    weights = jax.block_until_ready(kind.make_weights(config['model'], seed))
+    server = harness.start_server(config, kind, weights)
     sched = harness.schedule_for(mix, config, seed, seconds)
     em = loop.Emissions(serving['frame_ms'] / 1000)
 

@@ -1,5 +1,6 @@
-"""A small benchmark tree for CPU tests: the real metrics, reference and
-peaks, a model far below the published widths, and short mixes."""
+"""A small benchmark tree for CPU tests: the real metrics, references,
+model kinds and peaks, a model far below the published widths, and short
+mixes."""
 from __future__ import annotations
 
 import json
@@ -36,7 +37,7 @@ def make_tree(root: pathlib.Path) -> pathlib.Path:
     """``root/BENCHMARK.json`` with cells ``tiny.live`` and
     ``tiny.backlog``; returns the benchmark directory under it."""
     bench = root / 'bench'
-    for sub in ('metrics', 'reference'):
+    for sub in ('metrics', 'reference', 'models'):
         shutil.copytree(BENCH / sub, bench / sub,
                         ignore=shutil.ignore_patterns('__pycache__'))
     shutil.copy(BENCH / 'peaks.json', bench / 'peaks.json')
@@ -92,7 +93,7 @@ def committed_tree(root: pathlib.Path, cell_name: str,
         (BENCH / 'traffic' / f'{cell["traffic"]}.json').read_text())
     mix['warmup_s'] = warmup_s
     bench = root / 'bench'
-    for sub in ('metrics', 'reference'):
+    for sub in ('metrics', 'reference', 'models'):
         shutil.copytree(BENCH / sub, bench / sub,
                         ignore=shutil.ignore_patterns('__pycache__'))
     shutil.copy(BENCH / 'peaks.json', bench / 'peaks.json')

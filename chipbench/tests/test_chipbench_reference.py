@@ -3,23 +3,22 @@ pass, on the CPU at a small size (float32 throughout there)."""
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import harness
-from chipbench.bench import HERE, load_module
-from chipbench.flops import weight_bytes
-from chipbench.weights import make_weights
+from chipbench.bench import HERE, Spec, load_module
 
-MODEL = {'inputs': 13, 'hidden': 32, 'layers': 3, 'outputs': 16}
+MODEL = {'kind': 'peephole_lstm_ctc', 'inputs': 13, 'hidden': 32,
+         'layers': 3, 'outputs': 16}
+KIND = Spec(HERE.parent).model({'model': MODEL})
+make_weights = KIND.make_weights
 
 
 def test_reference_matches_the_programs_forward_pass():
     from repro.models import chipmunk_net
     ref = load_module(HERE / 'reference' / 'peephole_lstm_ctc.py')
     w = make_weights(MODEL, 2**35 + 1)
-    cfg = harness.program_config({
+    cfg, params = KIND.program({
         'name': 't', 'model': MODEL, 'dtype': 'float32',
-        'lstm_backend': 'xla_scan'})
-    params = harness.program_params(w)
-    assert 4 * params.num_params() == weight_bytes(MODEL)
+        'lstm_backend': 'xla_scan'}, w)
+    assert 4 * params.num_params() == KIND.weight_bytes(MODEL)
     x = np.random.default_rng(1).standard_normal((3, 40, 13), np.float32)
     want = np.moveaxis(np.asarray(chipmunk_net.forward(cfg, params,
                                                        jnp.asarray(x))), 0, 1)

@@ -58,6 +58,7 @@ def test_per_layer_readers_on_the_hand_made_trace():
         step_frames = np.array([64, 64, 64])
         step_streams = np.array([4, 4, 4])
         model = {'inputs': 123, 'hidden': 421, 'layers': 3, 'outputs': 62}
+        kind = load_module(HERE / 'models' / 'peephole_lstm_ctc.py')
         peaks = {'bf16_flops_per_s': 197e12, 'hbm_bytes_per_s': 819e9}
 
     read = lambda name: load_module(HERE / 'metrics' / f'{name}.py').read(Run)
